@@ -41,8 +41,8 @@ def main(quick: bool = False):
     for (b, h, hkv, d, page, nb) in cases:
         p = b * nb + 1
         q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(p, page, hkv, d)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(p, page, hkv, d)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(hkv, p, page, d)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(hkv, p, page, d)), jnp.float32)
         bt = jnp.asarray(rng.integers(0, p, (b, nb)), jnp.int32)
         ln = jnp.full((b,), nb * page, jnp.int32)
         t_k, out_k = _time(lambda: ops.paged_attention(q, kp, vp, bt, ln))
@@ -74,8 +74,8 @@ def main(quick: bool = False):
         nb = (ctx + s) // page
         p = nb + 2
         q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(p, page, hkv, d)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(p, page, hkv, d)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(hkv, p, page, d)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(hkv, p, page, d)), jnp.float32)
         bt = jnp.asarray(rng.permutation(p)[:b * nb].reshape(b, nb),
                          jnp.int32)
         cx = jnp.full((b,), ctx, jnp.int32)

@@ -23,6 +23,7 @@ from repro.core.gateway.gateway import Gateway
 from repro.core.sim.events import EventLoop
 from repro.engine.runner import ModelRunner
 from repro.engine.scheduler import Scheduler
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = [
     ("table1_distributed_kvcache", bench_kvcache.main),
@@ -50,6 +51,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="substring filter on suite name")
     args = ap.parse_args()
+    enable_compile_cache()
     failures = []
     for name, fn in SUITES:
         if args.only and args.only not in name:

@@ -74,7 +74,7 @@ def validate_wire_dtype(name: str) -> str:
 class CompressedPage:
     """One page's (k, v) arrays quantized to int8 with per-layer scales.
 
-    ``q_k``/``q_v`` keep the payload shape (L, page, Hkv, D); the scales
+    ``q_k``/``q_v`` keep the payload shape (L, Hkv, page, D); the scales
     are (L, 1, 1, 1) so dequantization is a single broadcast multiply.
     """
     q_k: np.ndarray

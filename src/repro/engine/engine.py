@@ -171,7 +171,9 @@ class InferenceEngine:
     def __init__(self, cfg: ModelConfig, ecfg: EngineConfig = None,
                  params=None, clock: Callable[[], float] = time.monotonic,
                  kv_pool_client=None, engine_id: str = "engine-0",
-                 seed: int = 0, ssd_pool=None):
+                 seed: int = 0, ssd_pool=None, device=None):
+        """``device`` pins this engine's params, KV pool and step inputs
+        to one accelerator (None: JAX's default device)."""
         ecfg = ecfg or EngineConfig()
         if not PM.pageable(cfg):
             raise ValueError(
@@ -182,7 +184,8 @@ class InferenceEngine:
         self.clock = clock
         self.kv_pool = kv_pool_client
         validate_wire_dtype(ecfg.wire_dtype)
-        self.runner = ModelRunner(cfg, ecfg, params=params, seed=seed)
+        self.runner = ModelRunner(cfg, ecfg, params=params, seed=seed,
+                                  device=device)
         # host-DRAM KV tier: device evictions cascade into it and
         # preemption swaps to it instead of recomputing
         self.host_pool = None

@@ -50,15 +50,25 @@ def pageable(cfg: ModelConfig) -> bool:
 
 
 class PagePool(NamedTuple):
-    k: jax.Array            # (L, P, page, Hkv, D)
+    """Head-major page pool: one KV head of one page is a contiguous
+    (page, D) tile, the block the Pallas kernels stage per grid step."""
+    k: jax.Array            # (L, Hkv, P, page, D)
     v: jax.Array
 
 
 def init_pool(cfg: ModelConfig, num_pages: int, page_size: int,
               dtype=jnp.float32) -> PagePool:
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
+    shape = (cfg.n_layers, cfg.n_kv_heads, num_pages, page_size,
              cfg.head_dim)
     return PagePool(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+def _write_kv(pages: jax.Array, pidx: jax.Array, slot: jax.Array,
+              x: jax.Array) -> jax.Array:
+    """Scatter token rows x (T, Hkv, D) into one layer's (Hkv, P, page,
+    D) pages at (pidx, slot) per token; pidx == P (out of range) drops
+    the row."""
+    return pages.at[:, pidx, slot].set(x.transpose(1, 0, 2), mode="drop")
 
 
 # ---------------------------------------------------------------- LoRA
@@ -207,10 +217,10 @@ def prefill_step(params, pool: PagePool, tokens: jax.Array,
         tok_pos = (ctx_len + jnp.arange(s))                    # (s,)
         in_range = jnp.arange(s) < chunk_len
         pidx = jnp.where(in_range, block_table[0, tok_pos // page_size],
-                         kp_l.shape[0])                        # OOB -> drop
+                         kp_l.shape[1])                        # OOB -> drop
         slot = tok_pos % page_size
-        kp_l = kp_l.at[pidx, slot].set(k[0], mode="drop")
-        vp_l = vp_l.at[pidx, slot].set(v[0], mode="drop")
+        kp_l = _write_kv(kp_l, pidx, slot, k[0])
+        vp_l = _write_kv(vp_l, pidx, slot, v[0])
         # chunk attends directly over the pages (ctx + chunk), no gather
         o = kops.paged_prefill(q, kp_l, vp_l, block_table, ctx1, chunk1,
                                impl=impl)
@@ -262,10 +272,10 @@ def decode_batch(params, pool: PagePool, tokens: jax.Array,
                             adapter_ids)
         pidx = jnp.where(active,
                          block_tables[bidx, positions // page_size],
-                         kp_l.shape[0])                        # OOB -> drop
+                         kp_l.shape[1])                        # OOB -> drop
         slot = positions % page_size
-        kp_l = kp_l.at[pidx, slot].set(k[:, 0], mode="drop")
-        vp_l = vp_l.at[pidx, slot].set(v[:, 0], mode="drop")
+        kp_l = _write_kv(kp_l, pidx, slot, k[:, 0])
+        vp_l = _write_kv(vp_l, pidx, slot, v[:, 0])
         o = kops.paged_attention(q[:, 0], kp_l, vp_l, block_tables,
                                  lengths, impl=impl)
         a = layers.attn_out(p_l["attn"], o[:, None])
@@ -328,7 +338,7 @@ def mixed_step(params, pool: PagePool,
 
     def body(x, xs):
         p_l, kp_l, vp_l = xs
-        oob = kp_l.shape[0]
+        oob = kp_l.shape[1]
         h = layers.rms_norm(x, p_l["ln1"], cfg.norm_eps)
         q, k, v = _qkv_lora_mixed(p_l["attn"], cfg, h,
                                   positions_flat[None], lora,
@@ -346,8 +356,8 @@ def mixed_step(params, pool: PagePool,
         pidx = jnp.concatenate([pidx_d, pidx_p.reshape(-1)])
         slot = jnp.concatenate([dec_positions % page_size,
                                 (pre_positions % page_size).reshape(-1)])
-        kp_l = kp_l.at[pidx, slot].set(k[0], mode="drop")
-        vp_l = vp_l.at[pidx, slot].set(v[0], mode="drop")
+        kp_l = _write_kv(kp_l, pidx, slot, k[0])
+        vp_l = _write_kv(vp_l, pidx, slot, v[0])
         # attention forks: decode rows vs chunk rows, both over pages
         o_dec = kops.paged_attention(q[0, :b], kp_l, vp_l,
                                      dec_block_tables, dec_lengths,
@@ -415,7 +425,7 @@ def spec_decode_step(params, pool: PagePool, spec_tokens: jax.Array,
 
     def body(x, xs):
         p_l, kp_l, vp_l = xs
-        oob = kp_l.shape[0]
+        oob = kp_l.shape[1]
         h = layers.rms_norm(x, p_l["ln1"], cfg.norm_eps)
         q, k, v = _qkv_lora_groups(p_l["attn"], cfg, h,
                                    positions_flat[None], lora,
@@ -425,10 +435,8 @@ def spec_decode_step(params, pool: PagePool, spec_tokens: jax.Array,
                                            positions // page_size],
                          oob)                                  # OOB -> drop
         slot = positions % page_size
-        kp_l = kp_l.at[pidx.reshape(-1), slot.reshape(-1)].set(
-            k[0], mode="drop")
-        vp_l = vp_l.at[pidx.reshape(-1), slot.reshape(-1)].set(
-            v[0], mode="drop")
+        kp_l = _write_kv(kp_l, pidx.reshape(-1), slot.reshape(-1), k[0])
+        vp_l = _write_kv(vp_l, pidx.reshape(-1), slot.reshape(-1), v[0])
         o = kops.paged_verify(
             q[0].reshape(b, sd, cfg.n_heads, cfg.head_dim), kp_l, vp_l,
             spec_block_tables, spec_ctx, spec_len, impl=impl)
@@ -492,7 +500,7 @@ def spec_mixed_step(params, pool: PagePool, spec_tokens: jax.Array,
 
     def body(x, xs):
         p_l, kp_l, vp_l = xs
-        oob = kp_l.shape[0]
+        oob = kp_l.shape[1]
         h = layers.rms_norm(x, p_l["ln1"], cfg.norm_eps)
         q, k, v = _qkv_lora_groups(p_l["attn"], cfg, h,
                                    positions_flat[None], lora,
@@ -510,8 +518,8 @@ def spec_mixed_step(params, pool: PagePool, spec_tokens: jax.Array,
         slot = jnp.concatenate(
             [(spec_positions % page_size).reshape(-1),
              (pre_positions % page_size).reshape(-1)])
-        kp_l = kp_l.at[pidx, slot].set(k[0], mode="drop")
-        vp_l = vp_l.at[pidx, slot].set(v[0], mode="drop")
+        kp_l = _write_kv(kp_l, pidx, slot, k[0])
+        vp_l = _write_kv(vp_l, pidx, slot, v[0])
         o_spec = kops.paged_verify(
             q[0, :b * sd].reshape(b, sd, h_, hd), kp_l, vp_l,
             spec_block_tables, spec_ctx, spec_len, impl=impl)

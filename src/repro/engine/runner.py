@@ -18,9 +18,15 @@ The runner also owns the page *payload* side of the distributed KV
 pool protocol: publishing freshly filled prompt pages (skipping the
 device→host copy when the pool already holds the hash) and writing
 fetched remote pages into local device pages.
+
+Placement: ``device`` pins one runner to one accelerator.  Params, pool
+and LoRA bank are committed there, and every method that builds step
+inputs runs under ``jax.default_device(device)``, so one process can
+drive several one-chip replicas, each on its own device.
 """
 from __future__ import annotations
 
+import functools
 import time
 import zlib
 from typing import Dict, List, Optional, Tuple
@@ -38,6 +44,16 @@ from repro.models import model as M
 from repro.models.config import ModelConfig
 
 
+def _on_device(method):
+    """Run a runner method with the runner's device as JAX's default, so
+    the step inputs it uploads land next to the params and pool."""
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        with jax.default_device(self.device):
+            return method(self, *args, **kwargs)
+    return wrapped
+
+
 class ModelRunner:
     """Turns declarative schedules into jitted forward passes."""
 
@@ -45,25 +61,31 @@ class ModelRunner:
     # per-suite delta (same pattern as Gateway.total_shed)
     total_device_wait_s = 0.0
 
-    def __init__(self, cfg: ModelConfig, ecfg, params=None, seed: int = 0):
+    def __init__(self, cfg: ModelConfig, ecfg, params=None, seed: int = 0,
+                 device: Optional[jax.Device] = None):
         self.cfg, self.ecfg = cfg, ecfg
+        self.device = device
         dtype = jnp.dtype(ecfg.dtype)
-        self.params = params if params is not None else M.init(
-            cfg, jax.random.PRNGKey(seed), dtype)
-        self.pool = PM.init_pool(cfg, ecfg.num_pages + 1, ecfg.page_size,
-                                 dtype)  # +1: OOB scratch page for drops
-        self.lora = PM.init_lora(cfg, ecfg.max_adapters, ecfg.lora_rank,
-                                 dtype)
+        with jax.default_device(device):
+            if params is None:
+                params = M.init(cfg, jax.random.PRNGKey(seed), dtype)
+            pool = PM.init_pool(cfg, ecfg.num_pages + 1, ecfg.page_size,
+                                dtype)  # +1: OOB scratch page for drops
+            lora = PM.init_lora(cfg, ecfg.max_adapters, ecfg.lora_rank,
+                                dtype)
+            self._key = jax.random.PRNGKey(seed + 1)
+            # adapter tiering (HBM bank <-> bounded host DRAM tier <->
+            # artifact store): weights are a pure function of (engine
+            # seed, adapter NAME) — never of the HBM slot — so eviction
+            # is always safe (re-load is byte-identical) and slot reuse
+            # can't leak.  ``_adapter_base_key`` is separate from the
+            # sampling key stream, which ``sample`` mutates.
+            self._adapter_base_key = jax.random.PRNGKey(seed + 2)
+        if device is not None:
+            params, pool, lora = jax.device_put((params, pool, lora), device)
+        self.params, self.pool, self.lora = params, pool, lora
         self._adapter_ids: Dict[str, int] = {}
         self._free_adapter_slots = list(range(1, ecfg.max_adapters))
-        self._key = jax.random.PRNGKey(seed + 1)
-        # adapter tiering (HBM bank <-> bounded host DRAM tier <->
-        # artifact store): weights are a pure function of (engine seed,
-        # adapter NAME) — never of the HBM slot — so eviction is always
-        # safe (re-load is byte-identical) and slot reuse can't leak.
-        # ``_adapter_base_key`` is separate from the sampling key
-        # stream, which ``sample`` mutates.
-        self._adapter_base_key = jax.random.PRNGKey(seed + 2)
         self._adapter_lru: Dict[str, int] = {}
         self._lru_tick = 0
         self._host_adapters: Dict[str, dict] = {}
@@ -137,6 +159,7 @@ class ModelRunner:
         self._lru_tick += 1
         self._adapter_lru[name] = self._lru_tick
 
+    @_on_device
     def register_adapter(self, name: str, weights: dict = None,
                          pinned=()) -> int:
         """Dynamic high-density LoRA registration (paper §3.2.1).
@@ -218,6 +241,7 @@ class ModelRunner:
         return idx
 
     # ---------------------------------------------------------- sampling
+    @_on_device
     def sample(self, logits, reqs, positions=None) -> np.ndarray:
         """``positions`` (absolute index of the token being produced,
         per row) switches to per-position keying (seed x position via
@@ -308,6 +332,7 @@ class ModelRunner:
         return pre_toks, pre_ctx, pre_chunk, pre_aids, pre_bts
 
     # ---------------------------------------------------------- execute
+    @_on_device
     def run_mixed(self, out: ScheduleOutput) -> Tuple[jax.Array, jax.Array]:
         """One fused decode+prefill pass; returns (dec_logits, pre_logits)."""
         ecfg = self.ecfg
@@ -324,6 +349,7 @@ class ModelRunner:
         self._inflight = (dec_logits, pre_logits)
         return dec_logits, pre_logits
 
+    @_on_device
     def run_decode(self, reqs: List[Request]) -> jax.Array:
         ecfg = self.ecfg
         toks, pos, bts, active, aids = self._decode_inputs(reqs)
@@ -360,6 +386,7 @@ class ModelRunner:
             aids[i] = self._aid(r)
         return toks, ctx, slen, bts, aids
 
+    @_on_device
     def run_spec(self, out: ScheduleOutput
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """One speculative verification pass.  Decode-only schedules
@@ -393,6 +420,7 @@ class ModelRunner:
         self._inflight = spec_logits
         return spec_logits, None
 
+    @_on_device
     def verify_drafts(self, spec_logits, reqs: List[Request],
                       spec: List[List[int]]) -> List[List[int]]:
         """Sample EVERY verification row with per-position keys and
@@ -423,6 +451,7 @@ class ModelRunner:
         return emitted
 
     # ------------------------------------------------ async decode step
+    @_on_device
     def run_decode_async(self, reqs: List[Request],
                          prev: Optional[dict]) -> jax.Array:
         """Dispatch a decode step WITHOUT blocking on the previous one.
@@ -481,6 +510,7 @@ class ModelRunner:
         self._inflight = tok_dev
         return tok_dev
 
+    @_on_device
     def run_prefill(self, work: PrefillWork) -> jax.Array:
         """One (possibly chunked) prefill for ONE request (two-phase)."""
         self._sync_inflight()
@@ -510,8 +540,8 @@ class ModelRunner:
     def page_bytes(self) -> int:
         """Raw (k + v) payload bytes of one page — what the host tier's
         capacity accounting and the transfer counters charge."""
-        k = self.pool.k
-        return int(2 * k[:, 0].size * k.dtype.itemsize)
+        k = self.pool.k                     # (L, Hkv, P, page, D)
+        return int(2 * k[:, :, 0].size * k.dtype.itemsize)
 
     def page_payload(self, pid: int):
         """Materialize one page's (k, v) arrays for a pool publish or a
@@ -520,11 +550,12 @@ class ModelRunner:
         ``np.array`` forces a real copy: host-tier entries outlive this
         step, and on CPU backends a zero-copy view could alias a
         donated buffer the next jitted call overwrites in place."""
-        return (np.array(self.pool.k[:, pid]),
-                np.array(self.pool.v[:, pid]))
+        return (np.array(self.pool.k[:, :, pid]),
+                np.array(self.pool.v[:, :, pid]))
 
+    @_on_device
     def write_remote_page(self, pid: int, k_page, v_page) -> None:
         """Install a page payload fetched from the distributed pool."""
         self.pool = PM.PagePool(
-            self.pool.k.at[:, pid].set(k_page),
-            self.pool.v.at[:, pid].set(v_page))
+            self.pool.k.at[:, :, pid].set(k_page),
+            self.pool.v.at[:, :, pid].set(v_page))

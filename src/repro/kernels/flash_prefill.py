@@ -9,6 +9,9 @@ chunked prefill (queries [q_offset, q_offset+Sq) attend over K/V
 [0, Sk)).  Off-diagonal tiles that the causal/window mask fully excludes
 are skipped before any compute.
 
+K/V arrive head-major, ``(B, Hkv, Sk, D)``, so a staged K/V block is a
+``(block_k, D)`` tile that meets Mosaic's (8, 128) block rule.
+
 Grid: (batch, kv_heads, q_blocks, kv_blocks), kv innermost (sequential)
 so the VMEM accumulator carries across KV tiles.
 """
@@ -52,8 +55,8 @@ def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(alive)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32).reshape(rows, d) * (d ** -0.5)
-        k = k_ref[0, :, 0].astype(jnp.float32)                 # (bk, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                    # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                # (rows, bk)
@@ -90,10 +93,10 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                   lengths: jax.Array, *, window: int = 0, q_offset: int = 0,
                   block_q: int = 128, block_k: int = 128,
                   interpret: bool = True) -> jax.Array:
-    """q: (B, Sq, H, D); k, v: (B, Sk, Hkv, D); lengths: (B,) valid K
-    tokens.  Returns (B, Sq, H, D)."""
+    """q: (B, Sq, H, D); k, v: (B, Hkv, Sk, D) head-major; lengths: (B,)
+    valid K tokens.  Returns (B, Sq, H, D)."""
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    hkv, sk = k.shape[1], k.shape[2]
     g = h // hkv
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -109,10 +112,10 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, g, block_q, d),
                          lambda b_, h_, qi, ki, ln: (b_, h_, 0, qi, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b_, h_, qi, ki, ln: (b_, ki, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b_, h_, qi, ki, ln: (b_, ki, h_, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda b_, h_, qi, ki, ln: (b_, h_, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda b_, h_, qi, ki, ln: (b_, h_, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, block_q, d),
                                lambda b_, h_, qi, ki, ln: (b_, h_, 0, qi, 0)),

@@ -1,9 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles shape padding (seq lens to block multiples, batch page tables),
-platform dispatch (interpret=True off-TPU so CPU tests execute the real
-kernel bodies), and an ``impl`` switch so every call site can be A/B'd
-against the pure-jnp oracle (impl="ref").
+platform dispatch (compiled on a TPU, interpret mode on the CPU so tests
+execute the real kernel bodies, an error on any other backend), and an
+``impl`` switch so every call site can be A/B'd against the pure-jnp
+oracle (impl="ref").
 """
 from __future__ import annotations
 
@@ -18,8 +19,18 @@ from repro.kernels.paged_attention import paged_attention as _paged
 from repro.kernels.paged_prefill import paged_prefill as _paged_pre
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas mode for the current backend: compiled Mosaic kernels on a
+    TPU, the interpreter on the CPU.  Any other backend is refused rather
+    than silently interpreted."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"backend {backend!r} is neither (use impl='ref')")
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -29,7 +40,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
         return ref.paged_attention_ref(q, k_pages, v_pages, block_tables,
                                        lengths)
     return _paged(q, k_pages, v_pages, block_tables, lengths,
-                  interpret=not _on_tpu())
+                  interpret=_interpret())
 
 
 def paged_prefill(q, k_pages, v_pages, block_tables, ctx_lens, chunk_lens,
@@ -46,7 +57,7 @@ def paged_prefill(q, k_pages, v_pages, block_tables, ctx_lens, chunk_lens,
     if s_p != s:
         q = jnp.pad(q, ((0, 0), (0, s_p - s), (0, 0), (0, 0)))
     out = _paged_pre(q, k_pages, v_pages, block_tables, ctx_lens,
-                     chunk_lens, block_q=bq, interpret=not _on_tpu())
+                     chunk_lens, block_q=bq, interpret=_interpret())
     return out[:, :s]
 
 
@@ -68,7 +79,9 @@ def paged_verify(q, k_pages, v_pages, block_tables, ctx_lens, draft_lens,
 def flash_attention(q, k, v, lengths, *, window: int = 0, q_offset: int = 0,
                     block_q: int = 128, block_k: int = 128,
                     impl: str = "pallas"):
-    """Causal/windowed prefill attention with automatic seq padding."""
+    """Causal/windowed prefill attention with automatic seq padding.
+    q: (B, Sq, H, D); k, v: (B, Sk, Hkv, D) — transposed here to the
+    kernel's head-major (B, Hkv, Sk, D)."""
     if impl == "ref":
         return ref.flash_prefill_ref(q, k, v, lengths, window=window,
                                      q_offset=q_offset)
@@ -83,8 +96,9 @@ def flash_attention(q, k, v, lengths, *, window: int = 0, q_offset: int = 0,
     if sk_p != sk:
         k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     out = _flash(q, k, v, lengths, window=window, q_offset=q_offset,
-                 block_q=bq, block_k=bk, interpret=not _on_tpu())
+                 block_q=bq, block_k=bk, interpret=_interpret())
     return out[:, :sq]
 
 

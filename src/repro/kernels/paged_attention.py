@@ -6,8 +6,11 @@ adaptation (vs. the CUDA original): block tables ride in as *scalar
 prefetch* so each grid step's BlockSpec index_map can stage exactly one
 KV page HBM->VMEM ahead of compute; the flash accumulator lives in VMEM
 scratch and persists across the (sequential, innermost) page dimension
-of the grid.  MXU alignment comes from the (G, page) x (page, D) matmul
-shapes — head_dim is 64..256 and page_size defaults to 64.
+of the grid.  The pool is head-major, ``(Hkv, P, page, D)``, so each
+staged KV block is a ``(page, D)`` tile: Mosaic needs a block's last two
+dims divisible by (8, 128) or equal to the array's, which a page of a
+multiple of 8 tokens at head_dim 128 meets.  The MXU sees
+(G, page) x (page, D) matmuls.
 
 Grid: (batch, kv_heads, num_blocks); one program handles the G = H/Hkv
 query-head group for one page of one sequence.
@@ -30,8 +33,8 @@ def _kernel(
     lengths_ref,        # (B,) int32
     # inputs (blocked)
     q_ref,              # (1, 1, G, D)
-    k_ref,              # (1, page, 1, D)
-    v_ref,              # (1, page, 1, D)
+    k_ref,              # (1, 1, page, D)
+    v_ref,              # (1, 1, page, D)
     # output
     o_ref,              # (1, 1, G, D)
     # scratch
@@ -57,8 +60,8 @@ def _kernel(
     def _compute():
         g, d = q_ref.shape[2], q_ref.shape[3]
         q = q_ref[0, 0].astype(jnp.float32) * (d ** -0.5)      # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)                 # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)                 # (page, D)
+        k = k_ref[0, 0].astype(jnp.float32)                    # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)                    # (page, D)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                # (G, page)
@@ -90,10 +93,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, lengths: jax.Array,
                     *, page_size: int = 0,
                     interpret: bool = True) -> jax.Array:
-    """q: (B, H, D); k_pages/v_pages: (P, page, Hkv, D);
+    """q: (B, H, D); k_pages/v_pages: (Hkv, P, page, D) head-major;
     block_tables: (B, NB) int32; lengths: (B,) int32 -> (B, H, D)."""
     b, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv, _, page, _ = k_pages.shape
     if page_size == 0:
         page_size = page
     assert page == page_size
@@ -106,7 +109,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         # their index to the last live page so the dead grid steps re-stage
         # an already-resident page instead of DMA'ing padding entries.
         last = jnp.maximum((ln[b_] + page_size - 1) // page_size - 1, 0)
-        return bt[b_, jnp.minimum(i_, last)], 0, h_, 0
+        return h_, bt[b_, jnp.minimum(i_, last)], 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -114,8 +117,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda b_, h_, i_, bt, ln:
                          (b_, h_, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
+            pl.BlockSpec((1, 1, page_size, d), kv_map),
+            pl.BlockSpec((1, 1, page_size, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h_, i_, bt, ln:
                                (b_, h_, 0, 0)),

@@ -48,8 +48,8 @@ def _kernel(
     chunk_lens_ref,     # (B,) int32 — valid tokens in the chunk
     # inputs (blocked)
     q_ref,              # (1, 1, G, bq, D)
-    k0_ref, k1_ref,     # (1, page, 1, D) — the staged page pair
-    v0_ref, v1_ref,     # (1, page, 1, D)
+    k0_ref, k1_ref,     # (1, 1, page, D) — the staged page pair
+    v0_ref, v1_ref,     # (1, 1, page, D)
     # output
     o_ref,              # (1, 1, G, bq, D)
     # scratch
@@ -85,9 +85,9 @@ def _kernel(
     @pl.when(alive)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32).reshape(rows, d) * (d ** -0.5)
-        k = jnp.concatenate([k0_ref[0, :, 0], k1_ref[0, :, 0]]).astype(
+        k = jnp.concatenate([k0_ref[0, 0], k1_ref[0, 0]]).astype(
             jnp.float32)                                       # (span, D)
-        v = jnp.concatenate([v0_ref[0, :, 0], v1_ref[0, :, 0]]).astype(
+        v = jnp.concatenate([v0_ref[0, 0], v1_ref[0, 0]]).astype(
             jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -132,12 +132,13 @@ def paged_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                   block_tables: jax.Array, ctx_lens: jax.Array,
                   chunk_lens: jax.Array, *, block_q: int = 128,
                   interpret: bool = True) -> jax.Array:
-    """q: (B, S, H, D) chunk queries; k_pages/v_pages: (P, page, Hkv, D);
+    """q: (B, S, H, D) chunk queries; k_pages/v_pages: (Hkv, P, page, D)
+    head-major;
     block_tables: (B, NB) int32; ctx_lens/chunk_lens: (B,) int32.
     Pages must already contain the chunk's own K/V.  Returns
     (B, S, H, D) with rows >= chunk_lens[b] zeroed."""
     b, s, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv, _, page, _ = k_pages.shape
     nb = block_tables.shape[1]
     g = h // hkv
     block_q = min(block_q, s)
@@ -150,11 +151,11 @@ def paged_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     def kv_map(which):
         def index_map(b_, h_, qi, ki, bt, cx, cl):
-            return (_live_page(bt, cx, cl, b_, PAIR * ki + which, nb,
-                               page), 0, h_, 0)
+            return (h_, _live_page(bt, cx, cl, b_, PAIR * ki + which, nb,
+                                   page), 0, 0)
         return index_map
 
-    kv_specs = [pl.BlockSpec((1, page, 1, d), kv_map(w))
+    kv_specs = [pl.BlockSpec((1, 1, page, d), kv_map(w))
                 for w in range(PAIR)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

@@ -1,8 +1,9 @@
 """Pure-jnp oracles for the Pallas kernels.
 
 These are the ground truth for tests/test_kernels.py shape/dtype sweeps
-(assert_allclose vs the interpret-mode kernels) and the reference path
-the engine falls back to on CPU.
+(assert_allclose vs the interpret-mode kernels) and the ``impl="ref"``
+path of every step function.  Page pools are head-major,
+``(Hkv, P, page, D)``, as the kernels read them.
 """
 from __future__ import annotations
 
@@ -12,25 +13,33 @@ import jax.numpy as jnp
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """(Hkv, P, page, D) pool + (B, NB) tables -> contiguous
+    (B, NB*page, Hkv, D) per-sequence K or V."""
+    hkv, _, page, d = pages.shape
+    b, nb = block_tables.shape
+    x = pages[:, block_tables]                           # (Hkv, B, NB, page, D)
+    return x.transpose(1, 2, 3, 0, 4).reshape(b, nb * page, hkv, d)
+
+
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         block_tables: jax.Array, lengths: jax.Array
                         ) -> jax.Array:
     """Decode attention over a paged KV cache.
 
     q:            (B, H, D)         one query token per sequence
-    k_pages:      (P, page, Hkv, D) global page pool
-    v_pages:      (P, page, Hkv, D)
+    k_pages:      (Hkv, P, page, D) global page pool, head-major
+    v_pages:      (Hkv, P, page, D)
     block_tables: (B, NB) int32     page ids per sequence (padded arbitrary)
     lengths:      (B,) int32        tokens in cache (incl. current token)
     returns:      (B, H, D)
     """
     b, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv, _, page, _ = k_pages.shape
     nb = block_tables.shape[1]
     g = h // hkv
-    # gather pages -> contiguous (B, NB*page, Hkv, D)
-    k = k_pages[block_tables].reshape(b, nb * page, hkv, d)
-    v = v_pages[block_tables].reshape(b, nb * page, hkv, d)
+    k = gather_pages(k_pages, block_tables)              # (B, NB*page, Hkv, D)
+    v = gather_pages(v_pages, block_tables)
     qf = (q.astype(jnp.float32) * d ** -0.5).reshape(b, hkv, g, d)
     logits = jnp.einsum("bhgd,bkhd->bhgk", qf, k.astype(jnp.float32))
     valid = jnp.arange(nb * page)[None] < lengths[:, None]        # (B, K)
@@ -47,8 +56,8 @@ def paged_prefill_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     q:            (B, S, H, D)      one prefill chunk per request; row j
                                     sits at absolute pos ctx_lens[b] + j
-    k_pages:      (P, page, Hkv, D) global page pool (chunk K/V already
-    v_pages:      (P, page, Hkv, D)  scattered in)
+    k_pages:      (Hkv, P, page, D) global page pool (chunk K/V already
+    v_pages:      (Hkv, P, page, D)  scattered in), head-major
     block_tables: (B, NB) int32     pages covering [0, ctx+chunk)
     ctx_lens:     (B,) int32        tokens in pages before the chunk
     chunk_lens:   (B,) int32        valid chunk tokens (rows beyond are
@@ -56,11 +65,11 @@ def paged_prefill_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     returns:      (B, S, H, D)
     """
     b, s, h, d = q.shape
-    _, page, hkv, _ = k_pages.shape
+    hkv, _, page, _ = k_pages.shape
     nb = block_tables.shape[1]
     g = h // hkv
-    k = k_pages[block_tables].reshape(b, nb * page, hkv, d)
-    v = v_pages[block_tables].reshape(b, nb * page, hkv, d)
+    k = gather_pages(k_pages, block_tables)
+    v = gather_pages(v_pages, block_tables)
     qpos = ctx_lens[:, None] + jnp.arange(s)[None]             # (B, S)
     kpos = jnp.arange(nb * page)
     total = (ctx_lens + chunk_lens)[:, None, None]

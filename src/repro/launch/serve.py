@@ -5,6 +5,13 @@ up N real JAX engines behind the AIBrix gateway (routing policy
 selectable), serves a synthetic batch of requests end-to-end, and prints
 the per-request latency metrics the paper's evaluations report.
 
+By default the model is the published configuration in bf16, with
+random weights, and each engine's KV pool is sized from ``--kv-gb``;
+engine *i* runs on ``jax.devices()[i % n]``, so a four-chip host serves
+four one-chip replicas.  ``--reduced`` swaps in the tiny float32 variant
+of the same family and a toy engine, which is what runs on a CPU (Pallas
+kernels in interpret mode).
+
 Prefill/decode disaggregation (paper §3.2.5) on the REAL data plane:
 ``--roles 2P2D`` builds 2 prefill + 2 decode JAX engines around a
 shared :class:`DistributedKVPool`.  Prefill engines publish each
@@ -55,9 +62,11 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_reduced_config
+from repro.configs import get_config, get_reduced_config
 from repro.core.gateway import Gateway
 from repro.core.kvcache.pool import DistributedKVPool
 from repro.core.lora.manager import AdapterSpec, LoRAController
@@ -71,6 +80,13 @@ from repro.core.sim.workloads import summarize
 from repro.engine import EngineConfig, InferenceEngine, Request, \
     SamplingParams
 from repro.engine.scheduler import DEFAULT_SLO_CLASSES
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import model as M
+
+# the tiny CPU engine ``--reduced`` serves (and the tests' usual size)
+REDUCED_ENGINE_KW = dict(page_size=8, num_pages=256, max_batch=4,
+                         max_pages_per_seq=32, chunk_size=32,
+                         dtype="float32")
 
 
 def parse_roles(spec: str, default_engines: int):
@@ -95,8 +111,25 @@ def auto_roles(cfg, n_engines: int, prompt_len: int, max_new: int,
     return ["prefill"] * rs.n_prefill + ["decode"] * rs.n_decode, rs
 
 
-def build_engines(cfg, roles, clock, ecfg_kw=None, gateway=None,
-                  force_pool=False, ssd_pool=None):
+def engine_kw(cfg, kv_gb: float) -> dict:
+    """EngineConfig sizes for a full-width model in bf16: the page pool
+    holds ``kv_gb`` GiB of K and V (one page costs 2 * layers * kv_heads
+    * page * head_dim * 2 bytes — 1.75 MiB for Qwen3-0.6B at 16-token
+    pages), and block tables cover 2048 tokens of context."""
+    page_size, max_context = 16, 2048
+    page_bytes = (2 * cfg.n_layers * cfg.n_kv_heads * page_size
+                  * cfg.head_dim * jnp.dtype(jnp.bfloat16).itemsize)
+    num_pages = int(kv_gb * (1 << 30)) // page_bytes - 1  # -1: scratch
+    if num_pages < 1:
+        raise ValueError(f"--kv-gb {kv_gb} holds no {page_bytes}-byte page")
+    return dict(page_size=page_size, num_pages=num_pages, max_batch=8,
+                chunk_size=256, max_pages_per_seq=max_context // page_size,
+                dtype="bfloat16")
+
+
+def build_engines(cfg, roles, clock, ecfg_kw, gateway=None,
+                  force_pool=False, ssd_pool=None, params=None,
+                  seed: int = 0):
     """A pod group under a RolePoolManager.
 
     Returns ``(engines dict, manager, pool)``.  The manager owns the
@@ -108,10 +141,16 @@ def build_engines(cfg, roles, clock, ecfg_kw=None, gateway=None,
     ``ssd_pool`` is a host-level :class:`SharedSSDPool` every engine
     attaches to (per-engine accounting views) instead of creating a
     private SSD tier.
+
+    Every engine is a replica of one model: it serves ``params`` (by
+    default initialized once from ``seed``), and engine *i* is placed
+    on ``jax.devices()[i % n]``.
     """
-    kw = dict(page_size=8, num_pages=256, max_batch=4,
-              max_pages_per_seq=32, chunk_size=32)
-    kw.update(ecfg_kw or {})
+    kw = dict(ecfg_kw)
+    devices = jax.devices()
+    if params is None:
+        params = M.init(cfg, jax.random.PRNGKey(seed),
+                        jnp.dtype(kw.get("dtype", "float32")))
     disagg = any(r != "mixed" for r in roles)
     pool = None
     if disagg or force_pool:
@@ -122,9 +161,9 @@ def build_engines(cfg, roles, clock, ecfg_kw=None, gateway=None,
     for i, role in enumerate(roles):
         eid = f"engine-{i}"
         engines[eid] = InferenceEngine(
-            cfg, EngineConfig(role=role, **kw), clock=clock,
-            kv_pool_client=pool, engine_id=eid, seed=0 if disagg else i,
-            ssd_pool=ssd_pool)
+            cfg, EngineConfig(role=role, **kw), params=params, clock=clock,
+            kv_pool_client=pool, engine_id=eid, seed=seed,
+            ssd_pool=ssd_pool, device=devices[i % len(devices)])
         manager.add_engine(eid, engines[eid], role)
     return engines, manager, pool
 
@@ -132,6 +171,12 @@ def build_engines(cfg, roles, clock, ecfg_kw=None, gateway=None,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny float32 variant of --arch on a "
+                         "toy engine (the CPU configuration); default is "
+                         "the published config in bf16")
+    ap.add_argument("--kv-gb", type=float, default=2.0,
+                    help="KV page pool per engine (GiB) at full width")
     ap.add_argument("--engines", type=int, default=None,
                     help="pod count for --roles mixed (default 2) or "
                          "--roles auto (default 4)")
@@ -230,7 +275,17 @@ def main() -> None:
     if args.adapters and args.roles != "mixed":
         ap.error("--adapters needs --roles mixed (the P->D handoff "
                  "path does not carry adapter state yet)")
-    cfg = get_reduced_config(args.arch)
+    enable_compile_cache()
+    if args.reduced:
+        cfg, ecfg_kw = get_reduced_config(args.arch), dict(REDUCED_ENGINE_KW)
+    else:
+        cfg = get_config(args.arch)
+        ecfg_kw = engine_kw(cfg, args.kv_gb)
+    dev = jax.devices()[0]
+    print(f"model {cfg.name}: {cfg.param_count() / 1e9:.3f}B params, "
+          f"head_dim={cfg.head_dim}, dtype={ecfg_kw['dtype']}, "
+          f"pages={ecfg_kw['num_pages']}x{ecfg_kw['page_size']} per engine "
+          f"on {len(jax.devices())} {dev.platform} device(s)")
     t0 = time.monotonic()
     clock = lambda: time.monotonic() - t0      # noqa: E731
     rebalancer = None
@@ -275,7 +330,7 @@ def main() -> None:
               f"{len(roles)} engine(s)")
     engines, manager, pool = build_engines(
         cfg, roles, clock,
-        ecfg_kw=dict(slo_aware=args.slo,
+        ecfg_kw=dict(ecfg_kw, slo_aware=args.slo,
                      host_cache_gb=args.host_cache_gb,
                      ssd_cache_gb=args.ssd_cache_gb,
                      wire_dtype=args.wire_dtype,

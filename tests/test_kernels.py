@@ -32,8 +32,8 @@ PAGED_CASES = [
 def test_paged_attention_matches_ref(b, h, hkv, d, page, nb, dtype):
     p = b * nb + 3
     q = _rand((b, h, d), dtype)
-    kp = _rand((p, page, hkv, d), dtype)
-    vp = _rand((p, page, hkv, d), dtype)
+    kp = _rand((hkv, p, page, d), dtype)
+    vp = _rand((hkv, p, page, d), dtype)
     bt = jnp.asarray(RNG.permutation(p)[:b * nb].reshape(b, nb), jnp.int32)
     lengths = jnp.asarray(RNG.integers(1, nb * page + 1, b), jnp.int32)
     out = paged_attention(q, kp, vp, bt, lengths)
@@ -47,8 +47,8 @@ def test_paged_attention_matches_ref(b, h, hkv, d, page, nb, dtype):
 def test_paged_attention_length_one():
     """Degenerate cache of a single token."""
     q = _rand((2, 4, 64), jnp.float32)
-    kp = _rand((8, 16, 2, 64), jnp.float32)
-    vp = _rand((8, 16, 2, 64), jnp.float32)
+    kp = _rand((2, 8, 16, 64), jnp.float32)
+    vp = _rand((2, 8, 16, 64), jnp.float32)
     bt = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
     lengths = jnp.ones(2, jnp.int32)
     out = paged_attention(q, kp, vp, bt, lengths)
@@ -73,7 +73,9 @@ def test_flash_prefill_matches_ref(b, sq, sk, h, hkv, d, win, qoff, dtype):
     k = _rand((b, sk, hkv, d), dtype)
     v = _rand((b, sk, hkv, d), dtype)
     lengths = jnp.asarray(RNG.integers(sk // 2, sk + 1, b), jnp.int32)
-    out = flash_prefill(q, k, v, lengths, window=win, q_offset=qoff,
+    # the kernel takes head-major K/V, (B, Hkv, Sk, D)
+    out = flash_prefill(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                        lengths, window=win, q_offset=qoff,
                         block_q=64, block_k=64)
     refv = kref.flash_prefill_ref(q, k, v, lengths, window=win,
                                   q_offset=qoff)

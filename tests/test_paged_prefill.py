@@ -41,8 +41,8 @@ def test_paged_prefill_matches_ref(b, h, hkv, d, page):
     cap = 192                           # tokens of paged capacity per seq
     nb = cap // page
     p = b * nb + 3
-    kp = _rand((p, page, hkv, d))
-    vp = _rand((p, page, hkv, d))
+    kp = _rand((hkv, p, page, d))
+    vp = _rand((hkv, p, page, d))
     bt = jnp.asarray(RNG.permutation(p)[:b * nb].reshape(b, nb), jnp.int32)
     ctx = jnp.asarray(RNG.integers(0, cap - s + 1, b), jnp.int32)
     chunk = jnp.asarray(RNG.integers(1, s + 1, b), jnp.int32)
@@ -57,15 +57,15 @@ def test_paged_prefill_zero_ctx_equals_flash():
     """A first chunk (ctx=0) must agree with contiguous flash prefill."""
     b, s, h, hkv, d, page = 1, 32, 4, 2, 32, 8
     nb = s // page
-    kp = _rand((nb + 1, page, hkv, d))
-    vp = _rand((nb + 1, page, hkv, d))
+    kp = _rand((hkv, nb + 1, page, d))
+    vp = _rand((hkv, nb + 1, page, d))
     bt = jnp.arange(nb, dtype=jnp.int32)[None]
     q = _rand((b, s, h, d))
     ctx = jnp.zeros(b, jnp.int32)
     chunk = jnp.full(b, s, jnp.int32)
     out = ops.paged_prefill(q, kp, vp, bt, ctx, chunk)
-    k = kp[:nb].reshape(1, s, hkv, d)
-    v = vp[:nb].reshape(1, s, hkv, d)
+    k = kref.gather_pages(kp, bt)
+    v = kref.gather_pages(vp, bt)
     refv = kref.flash_prefill_ref(q, k, v, jnp.full(b, s, jnp.int32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(refv),
                                atol=3e-5, rtol=3e-5)
@@ -74,8 +74,8 @@ def test_paged_prefill_zero_ctx_equals_flash():
 def test_paged_prefill_padding_rows_are_zero():
     b, s, h, hkv, d, page = 2, 16, 4, 2, 32, 8
     nb = 4
-    kp = _rand((b * nb + 1, page, hkv, d))
-    vp = _rand((b * nb + 1, page, hkv, d))
+    kp = _rand((hkv, b * nb + 1, page, d))
+    vp = _rand((hkv, b * nb + 1, page, d))
     bt = jnp.asarray(np.arange(b * nb).reshape(b, nb), jnp.int32)
     chunk = jnp.asarray([5, 12], jnp.int32)
     ctx = jnp.asarray([8, 0], jnp.int32)
