@@ -1,0 +1,9 @@
+"""Device layer: share of the traced window in which no operation ran
+on the chip (1 - union of device-op intervals / window)."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if not t or not t["window_s"] or not t["op_events"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
