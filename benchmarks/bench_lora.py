@@ -176,11 +176,13 @@ def real_engine_section(quick: bool = False):
         eng.run_until_idle()
     cold = sum(e.runner.adapter_loads for e in fleet.values())
     stall = sum(e.runner.adapter_load_s for e in fleet.values())
+    wait = sum(e.metrics().device_wait_s for e in fleet.values())
     finished = sum(1 for r in reqs if r.output_tokens)
     print(f"\nreal-jax,engines=2,adapters={n_adapters},requests={n_req}"
           f",finished={finished}"
           f",affinity_hit_rate={gw.stats.lora_affinity_hit_rate:.3f}"
-          f",cold_loads={cold},cold_load_s={stall:.3f}")
+          f",cold_loads={cold},cold_load_s={stall:.3f}"
+          f",device_wait_s={wait:.3f}")
     assert finished == n_req, "every routed request must finish"
     # the controller pre-placed the fleet, so routed requests land on a
     # resident pod far more often than the 1/2 an adapter-blind split

@@ -21,7 +21,6 @@ from benchmarks import (bench_autoscaling, bench_chaos, bench_coldstart,
                         bench_slo, bench_speculative, roofline)
 from repro.core.gateway.gateway import Gateway
 from repro.core.sim.events import EventLoop
-from repro.engine.runner import ModelRunner
 from repro.engine.scheduler import Scheduler
 from repro.launch.compile_cache import enable_compile_cache
 
@@ -60,7 +59,6 @@ def main() -> None:
         t0 = time.time()
         shed0 = Gateway.total_shed
         ev0 = EventLoop.total_events
-        wait0 = ModelRunner.total_device_wait_s
         lr0, lh0 = Gateway.total_lora_routed, Gateway.total_lora_hits
         lm0 = Scheduler.total_lora_miss
         try:
@@ -70,11 +68,6 @@ def main() -> None:
             # (it served LESS than the offered load it reports against)
             shed = Gateway.total_shed - shed0
             note = f" [gateway shed {shed} request(s)!]" if shed else ""
-            # host/device split: how long this suite's real engines sat
-            # blocked on device readbacks (0 for sim-only suites)
-            wait = ModelRunner.total_device_wait_s - wait0
-            if wait > 0:
-                note += f" [device wait {wait:.1f}s]"
             # multi-LoRA accounting: affinity hit rate of this suite's
             # LoRA-tagged routes + scheduler-level adapter misses (a
             # request that reached an engine without its adapter)

@@ -156,6 +156,13 @@ class AIRuntime:
             # the async engine loop hides
             "device_wait_s": float(m.device_wait_s),
             "host_overhead_frac": float(m.host_overhead_frac),
+            # engine steps taken, and the host seconds of each step
+            # phase (step_schedule_s, step_wait_s, step_prep_s,
+            # step_upload_s, step_launch_s, step_sample_s,
+            # step_readback_s, step_post_s; real engines only), kept
+            # whether or not the engine's tracer is on
+            "steps": float(m.steps),
+            **{f"step_{p}_s": float(v) for p, v in m.step_host_s.items()},
         }
 
     # ------------------------------------------------- engine management

@@ -55,6 +55,7 @@ from repro.engine.runner import ModelRunner
 from repro.engine.scheduler import (EngineMetrics, PENDING_TOKEN,  # noqa: F401
                                     ScheduleOutput, Scheduler,
                                     SchedulerConfig, window_throughput)
+from repro.engine.tracing import EngineTracer, phased
 from repro.models.config import ModelConfig
 
 
@@ -184,8 +185,11 @@ class InferenceEngine:
         self.clock = clock
         self.kv_pool = kv_pool_client
         validate_wire_dtype(ecfg.wire_dtype)
+        # in-program spans, step records and per-phase host counters
+        # (repro.engine.tracing); off until tracer.enable()
+        self.tracer = EngineTracer()
         self.runner = ModelRunner(cfg, ecfg, params=params, seed=seed,
-                                  device=device)
+                                  device=device, tracer=self.tracer)
         # host-DRAM KV tier: device evictions cascade into it and
         # preemption swaps to it instead of recomputing
         self.host_pool = None
@@ -228,9 +232,6 @@ class InferenceEngine:
         # {reqs, tok_dev (device), idxs (placeholder positions)};
         # resolved when the next step is dispatched (or at drain)
         self._pending: Optional[dict] = None
-        # wall time spent inside step(): with runner.device_wait_s it
-        # yields host_overhead_frac — the gap the async loop hides
-        self._step_wall_s = 0.0
         # predictive promotion: a daemon thread reads SSD pages off the
         # critical path; landed payloads queue here and are installed
         # into the host pool at step boundaries by the engine thread
@@ -418,15 +419,16 @@ class InferenceEngine:
         an unfinished prefill chunk produces none).  With
         ``async_loop`` the count for an overlapped decode step is the
         number DISPATCHED (read back when the next step is issued)."""
-        t0 = time.perf_counter()
-        try:
+        with self.tracer.step():
             self._flush_deferred_unloads()
             self._land_promotions()
             if self.ecfg.async_loop:
                 return self._step_async()
-            return self._exec(self.sched.schedule(self.clock()))
-        finally:
-            self._step_wall_s += time.perf_counter() - t0
+            return self._exec(self._schedule())
+
+    def _schedule(self) -> ScheduleOutput:
+        with self.tracer.phase("schedule"):
+            return self.sched.schedule(self.clock())
 
     def _exec(self, out: ScheduleOutput) -> int:
         """Execute one declarative schedule synchronously."""
@@ -475,8 +477,9 @@ class InferenceEngine:
                     produced += 1
         emitted = self.runner.verify_drafts(spec_logits, out.decode,
                                             out.spec)
-        produced += self.sched.on_spec_batch(out.decode, out.spec,
-                                             emitted, self.clock())
+        with self.tracer.phase("post"):
+            produced += self.sched.on_spec_batch(out.decode, out.spec,
+                                                 emitted, self.clock())
         return produced
 
     # ------------------------------------------------ async overlapped loop
@@ -489,14 +492,14 @@ class InferenceEngine:
         shape (prefill chunks, speculative drafts, idle) is a sync
         point: resolve first, re-plan on the real history, run the
         normal path."""
-        out = self.sched.schedule(self.clock())
+        out = self._schedule()
         if self._overlappable(out):
             return self._dispatch_async(out.decode)
         if self._pending is not None:
             self.drain_async()
             # resolution patched real tokens (and may have finished or
             # truncated requests) — the plan must be rebuilt on it
-            out = self.sched.schedule(self.clock())
+            out = self._schedule()
             if self._overlappable(out):
                 return self._dispatch_async(out.decode)
         return self._exec(out)
@@ -508,13 +511,15 @@ class InferenceEngine:
     def _dispatch_async(self, reqs: List[Request]) -> int:
         reqs = list(reqs)
         tok_dev = self.runner.run_decode_async(reqs, self._pending)
-        idxs = self.sched.on_decode_provisional(reqs, self.clock())
+        with self.tracer.phase("post"):
+            idxs = self.sched.on_decode_provisional(reqs, self.clock())
         prev, self._pending = self._pending, dict(
             reqs=reqs, tok_dev=tok_dev, idxs=idxs)
         if prev is not None:
             self._resolve_async(prev)
         return len(reqs)
 
+    @phased("post")
     def _resolve_async(self, rec: dict) -> None:
         """Read back one dispatched step's sampled tokens and patch
         them over the PENDING placeholders.  Stop-token finishes are
@@ -552,6 +557,7 @@ class InferenceEngine:
         if rec is not None:
             self._resolve_async(rec)
 
+    @phased("post")
     def _advance_prefill(self, work, logits) -> bool:
         """Advance one prefill chunk; True when it produced a token
         (prefill completed and its first token was sampled)."""
@@ -584,6 +590,7 @@ class InferenceEngine:
         self.sched.note_tokens(now, req.prompt_len + 1)
         return True
 
+    @phased("post")
     def _postprocess_decode(self, reqs, logits) -> None:
         # per-position sampling keys: the sample for a given (seed,
         # absolute position) is the same whether this row is decoded
@@ -616,9 +623,11 @@ class InferenceEngine:
         m.lora_cold_load_s = self.runner.adapter_load_s
         m.lora_evictions = self.runner.adapter_evictions
         m.lora_host_hits = self.runner.adapter_host_hits
-        if self._step_wall_s > 0:
+        m.steps = self.tracer.steps
+        m.step_host_s = dict(self.tracer.phase_s)
+        if self.tracer.step_s > 0:
             m.host_overhead_frac = min(max(
-                1.0 - self.runner.device_wait_s / self._step_wall_s,
+                1.0 - self.runner.device_wait_s / self.tracer.step_s,
                 0.0), 1.0)
         return m
 

@@ -68,8 +68,11 @@ class Request:
     # distributed pool — crash_takeover resumes from here
     ckpt_tokens: int = 0
 
-    # timestamps (engine clock)
+    # timestamps (engine clock): ``schedule_time`` is the latest
+    # admission, ``admit_time`` the first (a re-admission after a
+    # preemption keeps it)
     schedule_time: float = 0.0
+    admit_time: Optional[float] = None
     first_token_time: float = 0.0
     finish_time: float = 0.0
     token_times: List[float] = field(default_factory=list)

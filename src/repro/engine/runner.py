@@ -40,6 +40,7 @@ from repro.engine.request import Request
 from repro.engine.sampling import row_keys, sample
 from repro.engine.scheduler import PrefillWork, ScheduleOutput
 from repro.engine.speculative import accept_length
+from repro.engine.tracing import EngineTracer, phased
 from repro.models import model as M
 from repro.models.config import ModelConfig
 
@@ -57,14 +58,14 @@ def _on_device(method):
 class ModelRunner:
     """Turns declarative schedules into jitted forward passes."""
 
-    # process-wide device-wait accumulator: benchmarks/run.py prints the
-    # per-suite delta (same pattern as Gateway.total_shed)
-    total_device_wait_s = 0.0
-
     def __init__(self, cfg: ModelConfig, ecfg, params=None, seed: int = 0,
-                 device: Optional[jax.Device] = None):
+                 device: Optional[jax.Device] = None,
+                 tracer: Optional[EngineTracer] = None):
+        """``tracer`` is the owning engine's; a runner built alone makes
+        its own."""
         self.cfg, self.ecfg = cfg, ecfg
         self.device = device
+        self.tracer = tracer or EngineTracer()
         dtype = jnp.dtype(ecfg.dtype)
         with jax.default_device(device):
             if params is None:
@@ -119,8 +120,8 @@ class ModelRunner:
         self._spec_toks = np.zeros((b, sd), np.int32)
         self._spec_ctx = np.zeros(b, np.int32)
         self._spec_len = np.zeros(b, np.int32)
-        # seconds spent blocked on device readback (this runner / all
-        # runners) — the host-overhead signal the async loop shrinks
+        # seconds spent blocked on device readback — the host-overhead
+        # signal the async loop shrinks
         self.device_wait_s = 0.0
         # outputs of the most recent jitted call.  jnp.asarray may
         # zero-copy alias a host buffer on some backend/dtype combos
@@ -131,22 +132,23 @@ class ModelRunner:
 
     def _sync_inflight(self) -> None:
         if self._inflight is not None:
-            t0 = time.perf_counter()
-            jax.block_until_ready(self._inflight)
-            dt = time.perf_counter() - t0
-            self.device_wait_s += dt
-            ModelRunner.total_device_wait_s += dt
+            with self.tracer.phase("wait") as ph:
+                jax.block_until_ready(self._inflight)
+            self.device_wait_s += ph.dt
             self._inflight = None
 
     def readback(self, arr) -> np.ndarray:
         """Block on a device array and charge the wait to the
-        device-wait counters — the async loop's one sync point."""
-        t0 = time.perf_counter()
-        jax.block_until_ready(arr)
-        dt = time.perf_counter() - t0
-        self.device_wait_s += dt
-        ModelRunner.total_device_wait_s += dt
+        device-wait counter — the async loop's one sync point."""
+        with self.tracer.phase("readback") as ph:
+            jax.block_until_ready(arr)
+        self.device_wait_s += ph.dt
         return np.asarray(arr)
+
+    def _upload(self, *host) -> List[jax.Array]:
+        """One step's host input buffers, on the device."""
+        with self.tracer.phase("upload"):
+            return [jnp.asarray(a) for a in host]
 
     # ------------------------------------------------------------- LoRA
     def _adapter_key(self, name: str):
@@ -241,6 +243,7 @@ class ModelRunner:
         return idx
 
     # ---------------------------------------------------------- sampling
+    @phased("sample")
     @_on_device
     def sample(self, logits, reqs, positions=None) -> np.ndarray:
         """``positions`` (absolute index of the token being produced,
@@ -280,6 +283,7 @@ class ModelRunner:
         cap = -(-max(pages_needed, 1) // 4) * 4
         return min(cap, self.ecfg.max_pages_per_seq)
 
+    @phased("prep")
     def _decode_inputs(self, reqs):
         self._sync_inflight()
         ecfg = self.ecfg
@@ -303,6 +307,7 @@ class ModelRunner:
             aids[i] = self._aid(r)
         return toks, pos, bts, active, aids
 
+    @phased("prep")
     def _prefill_inputs(self, works: List[PrefillWork], s: int):
         self._sync_inflight()
         ecfg = self.ecfg
@@ -339,29 +344,35 @@ class ModelRunner:
         pre_toks, pre_ctx, pre_chunk, pre_aids, pre_bts = \
             self._prefill_inputs(out.prefills, out.pad_len)
         toks, pos, bts, active, aids = self._decode_inputs(out.decode)
-        dec_logits, pre_logits, self.pool = PM.mixed_step(
-            self.params, self.pool, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(bts), jnp.asarray(active), jnp.asarray(pre_toks),
-            jnp.asarray(pre_bts), jnp.asarray(pre_ctx),
-            jnp.asarray(pre_chunk), self.lora, jnp.asarray(aids),
-            jnp.asarray(pre_aids), cfg=self.cfg,
-            page_size=ecfg.page_size, impl=ecfg.impl)
+        (toks, pos, bts, active, pre_toks, pre_bts, pre_ctx, pre_chunk,
+         aids, pre_aids) = self._upload(
+            toks, pos, bts, active, pre_toks, pre_bts, pre_ctx, pre_chunk,
+            aids, pre_aids)
+        with self.tracer.launch("mixed_step",
+                                (bts.shape[1], pre_bts.shape[1]),
+                                out.prefills):
+            dec_logits, pre_logits, self.pool = PM.mixed_step(
+                self.params, self.pool, toks, pos, bts, active, pre_toks,
+                pre_bts, pre_ctx, pre_chunk, self.lora, aids, pre_aids,
+                cfg=self.cfg, page_size=ecfg.page_size, impl=ecfg.impl)
         self._inflight = (dec_logits, pre_logits)
         return dec_logits, pre_logits
 
     @_on_device
     def run_decode(self, reqs: List[Request]) -> jax.Array:
         ecfg = self.ecfg
-        toks, pos, bts, active, aids = self._decode_inputs(reqs)
-        logits, self.pool = PM.decode_batch(
-            self.params, self.pool, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(bts), jnp.asarray(active), self.lora,
-            jnp.asarray(aids), cfg=self.cfg, page_size=ecfg.page_size,
-            impl=ecfg.impl)
+        toks, pos, bts, active, aids = self._upload(
+            *self._decode_inputs(reqs))
+        with self.tracer.launch("decode_batch", (bts.shape[1], 0)):
+            logits, self.pool = PM.decode_batch(
+                self.params, self.pool, toks, pos, bts, active, self.lora,
+                aids, cfg=self.cfg, page_size=ecfg.page_size,
+                impl=ecfg.impl)
         self._inflight = logits
         return logits
 
     # -------------------------------------------------- speculative step
+    @phased("prep")
     def _spec_inputs(self, reqs: List[Request], spec: List[List[int]]):
         self._sync_inflight()
         ecfg = self.ecfg
@@ -402,24 +413,30 @@ class ModelRunner:
                 self._prefill_inputs(out.prefills, out.pad_len)
             toks, ctx, slen, bts, aids = self._spec_inputs(
                 out.decode, out.spec)
-            spec_logits, pre_logits, self.pool = PM.spec_mixed_step(
-                self.params, self.pool, jnp.asarray(toks),
-                jnp.asarray(ctx), jnp.asarray(slen), jnp.asarray(bts),
-                jnp.asarray(pre_toks), jnp.asarray(pre_bts),
-                jnp.asarray(pre_ctx), jnp.asarray(pre_chunk), self.lora,
-                jnp.asarray(aids), jnp.asarray(pre_aids), cfg=self.cfg,
-                page_size=ecfg.page_size, impl=ecfg.impl)
+            (toks, ctx, slen, bts, pre_toks, pre_bts, pre_ctx, pre_chunk,
+             aids, pre_aids) = self._upload(
+                toks, ctx, slen, bts, pre_toks, pre_bts, pre_ctx, pre_chunk,
+                aids, pre_aids)
+            with self.tracer.launch("spec_mixed_step",
+                                    (bts.shape[1], pre_bts.shape[1]),
+                                    out.prefills):
+                spec_logits, pre_logits, self.pool = PM.spec_mixed_step(
+                    self.params, self.pool, toks, ctx, slen, bts, pre_toks,
+                    pre_bts, pre_ctx, pre_chunk, self.lora, aids, pre_aids,
+                    cfg=self.cfg, page_size=ecfg.page_size, impl=ecfg.impl)
             self._inflight = (spec_logits, pre_logits)
             return spec_logits, pre_logits
-        toks, ctx, slen, bts, aids = self._spec_inputs(out.decode, out.spec)
-        spec_logits, self.pool = PM.spec_decode_step(
-            self.params, self.pool, jnp.asarray(toks), jnp.asarray(ctx),
-            jnp.asarray(slen), jnp.asarray(bts), self.lora,
-            jnp.asarray(aids), cfg=self.cfg, page_size=ecfg.page_size,
-            impl=ecfg.impl)
+        toks, ctx, slen, bts, aids = self._upload(
+            *self._spec_inputs(out.decode, out.spec))
+        with self.tracer.launch("spec_decode_step", (bts.shape[1], 0)):
+            spec_logits, self.pool = PM.spec_decode_step(
+                self.params, self.pool, toks, ctx, slen, bts, self.lora,
+                aids, cfg=self.cfg, page_size=ecfg.page_size,
+                impl=ecfg.impl)
         self._inflight = spec_logits
         return spec_logits, None
 
+    @phased("sample")
     @_on_device
     def verify_drafts(self, spec_logits, reqs: List[Request],
                       spec: List[List[int]]) -> List[List[int]]:
@@ -465,6 +482,29 @@ class ModelRunner:
         NEXT step can consume without a sync.  Returns the sampled
         tokens (device)."""
         ecfg = self.ecfg
+        host, rows, srcs = self._async_inputs(reqs, prev)
+        toks, pos, bts, active, aids, seeds, pos1, temps, tops = \
+            self._upload(*host)
+        if rows:
+            with self.tracer.phase("upload"):
+                toks = toks.at[jnp.asarray(np.asarray(rows))].set(
+                    prev["tok_dev"][jnp.asarray(np.asarray(srcs))])
+        with self.tracer.launch("decode_batch", (bts.shape[1], 0)):
+            logits, self.pool = PM.decode_batch(
+                self.params, self.pool, toks, pos, bts, active, self.lora,
+                aids, cfg=self.cfg, page_size=ecfg.page_size,
+                impl=ecfg.impl)
+        with self.tracer.phase("sample"):
+            tok_dev = sample(logits, self._key, temps, top_k=0, top_p=tops,
+                             keys=row_keys(seeds, pos1))
+        self._inflight = tok_dev
+        return tok_dev
+
+    @phased("prep")
+    def _async_inputs(self, reqs: List[Request], prev: Optional[dict]):
+        """The async decode step's host inputs, and the rows whose input
+        token is still on the device with their rows in ``prev``."""
+        ecfg = self.ecfg
         b = ecfg.max_batch
         nb = self._bt_width(max((self._pages_for(
             r.prompt_len + len(r.output_tokens)) for r in reqs),
@@ -495,43 +535,35 @@ class ModelRunner:
             temps[i] = r.sampling.temperature
             tops[i] = r.sampling.top_p
             seeds[i] = r.sampling.seed
-        tok_in = jnp.asarray(toks)
-        if rows:
-            tok_in = tok_in.at[jnp.asarray(np.asarray(rows))].set(
-                prev["tok_dev"][jnp.asarray(np.asarray(srcs))])
-        logits, self.pool = PM.decode_batch(
-            self.params, self.pool, tok_in, jnp.asarray(pos),
-            jnp.asarray(bts), jnp.asarray(active), self.lora,
-            jnp.asarray(aids), cfg=self.cfg, page_size=ecfg.page_size,
-            impl=ecfg.impl)
-        keys = row_keys(jnp.asarray(seeds), jnp.asarray(pos + 1))
-        tok_dev = sample(logits, self._key, jnp.asarray(temps),
-                         top_k=0, top_p=jnp.asarray(tops), keys=keys)
-        self._inflight = tok_dev
-        return tok_dev
+        return ((toks, pos, bts, active, aids, seeds, pos + 1, temps, tops),
+                rows, srcs)
 
     @_on_device
     def run_prefill(self, work: PrefillWork) -> jax.Array:
         """One (possibly chunked) prefill for ONE request (two-phase)."""
-        self._sync_inflight()
         ecfg = self.ecfg
         req, s, c = work.req, work.pad_len, work.chunk_len
-        if s == ecfg.chunk_size:
-            toks = self._pre_toks[:1]
-            toks[:] = 0
-        else:
-            toks = np.zeros((1, s), np.int32)
-        toks[0, :c] = req.prompt_tokens[work.start:work.start + c]
-        nb = self._bt_width(self._pages_for(work.start + c))
-        bt = self._pre_bts[:1, :nb]
-        bt[:] = ecfg.num_pages              # OOB scratch page
-        n = min(len(req.page_ids), nb)
-        bt[0, :n] = req.page_ids[:n]
-        logits, self.pool = PM.prefill_step(
-            self.params, self.pool, jnp.asarray(toks), jnp.asarray(bt),
-            jnp.int32(work.start), jnp.int32(c),
-            self.lora, jnp.asarray([self._aid(req)], jnp.int32),
-            cfg=self.cfg, page_size=ecfg.page_size, impl=ecfg.impl)
+        with self.tracer.phase("prep"):
+            self._sync_inflight()
+            if s == ecfg.chunk_size:
+                toks = self._pre_toks[:1]
+                toks[:] = 0
+            else:
+                toks = np.zeros((1, s), np.int32)
+            toks[0, :c] = req.prompt_tokens[work.start:work.start + c]
+            nb = self._bt_width(self._pages_for(work.start + c))
+            bt = self._pre_bts[:1, :nb]
+            bt[:] = ecfg.num_pages              # OOB scratch page
+            n = min(len(req.page_ids), nb)
+            bt[0, :n] = req.page_ids[:n]
+            aid = np.asarray([self._aid(req)], np.int32)
+        toks, bt, start, clen, aid = self._upload(
+            toks, bt, np.int32(work.start), np.int32(c), aid)
+        with self.tracer.launch("prefill_step", (0, nb), [work]):
+            logits, self.pool = PM.prefill_step(
+                self.params, self.pool, toks, bt, start, clen, self.lora,
+                aid, cfg=self.cfg, page_size=ecfg.page_size,
+                impl=ecfg.impl)
         self._inflight = logits
         return logits
 

@@ -218,6 +218,10 @@ class EngineMetrics:
     # time spent on host-side work — the gap the async loop hides
     device_wait_s: float = 0.0
     host_overhead_frac: float = 0.0
+    # engine steps taken, and host seconds by step phase (the engine's
+    # repro.engine.tracing.PHASES, each phase's self time)
+    steps: int = 0
+    step_host_s: Dict[str, float] = field(default_factory=dict)
 
 
 @dataclass
@@ -361,6 +365,8 @@ class SchedulerCore:
 
     def note_admitted(self, req: Request, now: float) -> None:
         req.schedule_time = now
+        if req.admit_time is None:
+            req.admit_time = now
         self._m["admitted"] += 1
         self._q_ewma = 0.9 * self._q_ewma + 0.1 * req.queue_time
 

@@ -11,8 +11,8 @@ from repro.core.optimizer import (GPUOptimizer, ProfileTable, WorkloadBucket,
 from repro.core.optimizer.gpu_optimizer import DemandBucket, LoadMonitor
 from repro.core.orchestration.cluster import (ClusterManager, EngineGroup,
                                               GroupSpec, PodState)
-from repro.core.runtime.sidecar import (ColdStartManager, ModelArtifact,
-                                        load_time_s)
+from repro.core.runtime.sidecar import (AIRuntime, ColdStartManager,
+                                        ModelArtifact, load_time_s)
 from repro.core.sim.events import EventLoop
 
 
@@ -172,6 +172,26 @@ def test_silent_degradation_needs_history():
 def test_streaming_loader_beats_sequential():
     for tier in ("remote", "local", "dram"):
         assert load_time_s(14e9, tier, True) < load_time_s(14e9, tier, False)
+
+
+def test_sidecar_exports_step_counters():
+    """The engine's step count and per-phase host seconds reach the
+    sidecar's scrape with the tracer off."""
+    from repro.configs import get_reduced_config
+    from repro.engine import (EngineConfig, InferenceEngine, Request,
+                              SamplingParams)
+    from repro.engine.tracing import PHASES
+    eng = InferenceEngine(get_reduced_config("qwen3-0.6b"), EngineConfig(
+        page_size=8, num_pages=32, max_batch=2, max_pages_per_seq=8,
+        chunk_size=16))
+    eng.submit(Request(prompt_tokens=list(range(1, 20)),
+                       sampling=SamplingParams(max_new_tokens=3)))
+    eng.run_until_idle()
+    s = AIRuntime(eng).scrape()
+    assert s["steps"] == eng.tracer.steps > 0
+    for p in PHASES:
+        assert s[f"step_{p}_s"] == eng.tracer.phase_s[p] >= 0.0
+    assert s["step_launch_s"] > 0.0 and s["step_readback_s"] > 0.0
 
 
 def test_cold_start_manager_prefers_fastest_tier():
