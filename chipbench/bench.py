@@ -22,6 +22,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from chipbench import arch
 from chipbench import weights as W
 from chipbench.traffic import Arrival
 
@@ -96,32 +97,16 @@ class ServeResult:
     window_compiles: int = 0
     trace: Optional[dict] = None
     drained_at: float = 0.0
-
-
-def check_config(cdoc: dict, cfg) -> None:
-    """The program must run the configuration as the file states it."""
-    want = {"n_layers": cdoc["num_hidden_layers"],
-            "d_model": cdoc["hidden_size"],
-            "n_heads": cdoc["num_attention_heads"],
-            "n_kv_heads": cdoc["num_key_value_heads"],
-            "head_dim": cdoc["head_dim"], "d_ff": cdoc["intermediate_size"],
-            "vocab_size": cdoc["vocab_size"],
-            "qk_norm": cdoc["qk_norm"], "qkv_bias": cdoc["attention_bias"],
-            "tie_embeddings": cdoc["tie_word_embeddings"],
-            "norm_eps": cdoc["rms_norm_eps"],
-            "rope_theta": float(cdoc["rope_theta"])}
-    got = {k: getattr(cfg, k) for k in want}
-    bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-    if bad or cfg.family != "dense" or cfg.act != cdoc["hidden_act"]:
-        raise ValueError(f"program config {cfg.name} departs from "
-                         f"{cdoc['name']}: {bad}")
+    # engine.metrics() as the window opened and as it closed: a reader
+    # of a program counter takes its delta over the window
+    counters_open: object = None
+    counters_close: object = None
 
 
 class Bench:
     def __init__(self, cdoc: dict, seed: int, device=None):
         import jax
         import jax.numpy as jnp
-        from repro.configs import get_config, get_reduced_config
         from repro.core.gateway import Gateway
         from repro.launch.serve import build_engines
         from repro.models import model as M
@@ -129,10 +114,9 @@ class Bench:
 
         self.cdoc = cdoc
         self.device = device or jax.devices()[0]
-        prog = cdoc["program"]
-        self.cfg = cfg = (get_reduced_config(prog["model"])
-                          if prog.get("reduced") else get_config(prog["model"]))
-        check_config(cdoc, cfg)
+        module = arch.load(cdoc)
+        self.cfg = cfg = module.program_config(cdoc)
+        module.check(cdoc, cfg)
         dtype = jnp.dtype(cdoc["program"]["dtype"])
         key = jax.random.PRNGKey(int(np.uint32(
             np.random.SeedSequence(seed_words(seed)).generate_state(1)[0])))
@@ -244,8 +228,10 @@ class Bench:
               spans: Optional[Callable[[str], object]] = None
               ) -> ServeResult:
         """Warm-up traffic, the window, and the drain.  ``trace_slice``
-        (start, stop) in seconds from the window's opening brackets a
-        profiler trace, and the run follows requests until it has
+        (start, stop, cap) in seconds from the window's opening brackets
+        a profiler trace: it stops at ``stop`` where a traced step has
+        carried a prefill chunk by then, else after the first step that
+        does, or at ``cap``.  The run follows requests until it has
         stopped; ``spans(name)`` opens a host span."""
         import jax
         from repro.engine import Request, SamplingParams
@@ -261,11 +247,10 @@ class Bench:
         res = ServeResult(records, self.steps,
                           self._origin + open_e, self._origin + close_e,
                           lambda t: t - self._origin)
-        trace_on = trace_off = None
+        trace_on = trace_off = trace_cap = None
         if trace_slice is not None:
-            trace_on, trace_off = (open_e + trace_slice[0],
-                                   open_e + trace_slice[1])
-        tracer = None
+            trace_on, trace_off, trace_cap = (open_e + t for t in trace_slice)
+        tracer, traced0 = None, 0
         wait0 = step_wall = 0.0
         nsteps, opened, closed, i, n = 0, False, False, 0, len(records)
         while True:
@@ -273,11 +258,13 @@ class Bench:
             if not opened and now >= open_e:
                 opened = True
                 wait0 = eng.runner.device_wait_s
+                res.counters_open = eng.metrics()
             if trace_on is not None and tracer is None and now >= trace_on:
                 jax.block_until_ready(eng.pool)
-                tracer = trace_start()
+                tracer, traced0 = trace_start(), len(self.steps)
             if tracer is not None and trace_off is not None \
-                    and now >= trace_off:
+                    and now >= trace_off and (now >= trace_cap or any(
+                        s.chunks for s in self.steps[traced0:])):
                 jax.block_until_ready(eng.pool)
                 res.trace = trace_stop(tracer)
                 trace_off = None
@@ -318,6 +305,7 @@ class Bench:
                 closed = True
                 res.device_wait_s = eng.runner.device_wait_s - wait0
                 res.step_wall_s, res.step_count = step_wall, nsteps
+                res.counters_close = eng.metrics()
             if closed and trace_off is None and (
                     now >= stop_e or all(_settled(rec) for rec in window)):
                 break

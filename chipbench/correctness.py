@@ -21,7 +21,7 @@ from pathlib import Path
 
 import numpy as np
 
-from chipbench import reference
+from chipbench import arch, reference
 from chipbench.traffic import rng_for
 
 LIMITS_DIR = Path(__file__).resolve().parent / "limits"
@@ -57,16 +57,12 @@ def pick(records, seed: int, n: int = SAMPLE_REQUESTS):
     return [done[0]] + [rest[i] for i in order[:n - 1]]
 
 
-def _padded(n: int) -> int:
-    b = reference.BLOCK_Q
-    return -(-n // b) * b
-
-
 def gap_readings(c: dict, weights, samples, seq_len: int, sel_len: int,
                  controls=()) -> dict:
     """Widest served-token gap, and for each control precision the
     widest gap of the token it puts first, over ``samples``."""
-    seq_len = _padded(seq_len)
+    block = arch.load(c).BLOCK_Q
+    seq_len = -(-seq_len // block) * block
     served, ctl = [], {q: [] for q in controls}
     for r in samples:
         out = list(r.output)

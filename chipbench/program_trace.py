@@ -50,20 +50,24 @@ def load(trace_dir: str) -> List[dict]:
                       recursive=True)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    pd = jax.profiler.ProfileData.from_file(paths[0])
-    spans = []
+    return spans(jax.profiler.ProfileData.from_file(paths[0]))
+
+
+def spans(pd) -> List[dict]:
+    """The program's host spans in a read ``ProfileData``, by start."""
+    out = []
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
             continue
         for line in plane.lines:
             for ev in line.events:
                 if ev.name.startswith(PREFIXES):
-                    spans.append({
+                    out.append({
                         "name": ev.name, "start": float(ev.start_ns),
                         "dur": float(ev.duration_ns),
                         "stats": {k: v for k, v in ev.stats
                                   if isinstance(v, (int, str))}})
-    return sorted(spans, key=lambda s: s["start"])
+    return sorted(out, key=lambda s: s["start"])
 
 
 def _union(intervals) -> List[tuple]:

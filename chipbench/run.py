@@ -19,12 +19,17 @@ drain, and the check of the served tokens against the plain reference
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics, read by ``chipbench/metrics/<metric>.py`` from the
-harness's own spans and counters over the window, and from a profiler
-trace of the first seconds of the drain, under the same load: stopping
-the profiler stalls the host for seconds, which would distort the
-window's requests.  The last stdout line is one JSON object;
-the numbers compared for ``correct`` come last in it, under ``checks``,
-and again as the last lines on stderr.
+harness's own spans and counters over the window, from the program's
+(its step records, and ``engine.metrics()`` as the window opened and
+closed), and from a profiler trace of the first seconds of the drain
+(3 s, or until a traced step has carried a prefill chunk, at most 10
+s), under the same load: stopping the profiler stalls the host for
+seconds, which would distort the window's requests.  Only ``--trace
+1`` switches the engine's tracer on.  The trace's kernels are the
+``KERNELS`` of the configuration's architecture module
+(``chipbench/arch``).  The last stdout line is one JSON object; the
+numbers compared for ``correct`` come last in it, under ``checks``, and
+again as the last lines on stderr.
 """
 from __future__ import annotations
 
@@ -41,6 +46,10 @@ from types import SimpleNamespace  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 TRACE_S = 3.0               # length of the traced slice
+# ... or longer, until a traced step has carried a prefill chunk: a fast
+# engine can finish the window's prefills before the slice opens (the
+# long-docs timeline has no arrival from 49.7 to 55.0 s of any seed)
+TRACE_CAP_S = 10.0
 
 
 def fail(msg: str, code: int = 3):
@@ -122,12 +131,6 @@ def span_wrappers(bench):
     return TA
 
 
-# kernels by the HLO op name they carry in the trace (their jitted
-# wrapper's name: %paged_attention.13, %paged_prefill.15)
-KERNELS = {"paged_attention": r"^paged_attention$",
-           "paged_prefill": r"^paged_prefill$"}
-
-
 def listed(metrics: list, cell: str) -> list:
     """The metrics of ``BENCHMARK.json`` that a cell reports."""
     return [m for m in metrics if cell in m.get("workloads", [cell])]
@@ -138,7 +141,7 @@ def run_cell(name: str, cell: dict, cdoc: dict, mix: dict, limits: dict,
              faults=(), controls=()) -> dict:
     """Everything after the look for a chip: set-up, window, drain,
     metrics and the check; returns the result object."""
-    from chipbench import correctness, traffic, window
+    from chipbench import arch, correctness, traffic, window
     from chipbench import trace as T
     from chipbench.bench import Bench
 
@@ -148,8 +151,11 @@ def run_cell(name: str, cell: dict, cdoc: dict, mix: dict, limits: dict,
     bench.warm_programs(mix["context_cap"])
     arrivals = traffic.timeline(mix, seed, seconds, cdoc["vocab_size"])
     spans = span_wrappers(bench) if trace else None
+    if trace:
+        bench.engine.tracer.enable()
     res = bench.serve(arrivals, mix["warmup_s"], seconds, mix["drain_s"],
-                      (seconds, seconds + TRACE_S) if trace else None,
+                      (seconds, seconds + TRACE_S, seconds + TRACE_CAP_S)
+                      if trace else None,
                       spans)
     setup_s = res.window_open - t0
     memory = dict(device.memory_stats() or {})
@@ -161,11 +167,14 @@ def run_cell(name: str, cell: dict, cdoc: dict, mix: dict, limits: dict,
     if res.trace is not None:
         events = T.load(res.trace["dir"])
         reduced = T.reduce(events, (res.trace["t_stop"]
-                                    - res.trace["t_start"]) * 1e9, KERNELS)
+                                    - res.trace["t_start"]) * 1e9,
+                           arch.load(cdoc).KERNELS)
     ctx = SimpleNamespace(
         cdoc=cdoc, mix=mix, records=res.records, serve=res,
         open_e=open_e, close_e=open_e + seconds, stop_e=res.drained_at,
         memory=memory, trace=reduced, peak=peaks(device) if trace else None,
+        step_records=list(bench.engine.tracer.records),
+        counters_open=res.counters_open, counters_close=res.counters_close,
         window_steps=[s for s in res.steps
                       if res.window_open <= s.t < res.window_close],
         traced_steps=[s for s in res.steps if res.trace is not None
@@ -201,7 +210,8 @@ def run_cell(name: str, cell: dict, cdoc: dict, mix: dict, limits: dict,
     if reduced is not None:
         dev["busy_s"], dev["window_s"] = reduced["busy_s"], reduced["window_s"]
         out["breakdown"] = {"device_ops": reduced["device_ops"],
-                            "idle_gaps": reduced["idle_gaps"]}
+                            "idle_gaps": reduced["idle_gaps"],
+                            "host_gaps": reduced["program"]["host_gaps"]}
     out["_diag"] = {
         "summary": summary, "setup_s": setup_s, "readings": readings,
         "late_p50_ms": 1e3 * late[len(late) // 2] if late else None,
@@ -212,7 +222,10 @@ def run_cell(name: str, cell: dict, cdoc: dict, mix: dict, limits: dict,
             k: reduced[k] for k in ("kernel_s", "kernel_calls",
                                     "step_programs", "step_busy_s",
                                     "step_busy_by",
-                                    "op_events", "devices")}}
+                                    "op_events", "devices")},
+        "program_trace": None if reduced is None else {
+            k: reduced["program"][k] for k in ("modules", "step_spans",
+                                               "host_idle_s", "mismatched")}}
     out["checks"] = checks
     return out
 

@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import weights
+from chipbench import arch, weights
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.load(open(ROOT / "BENCHMARK.json"))
@@ -17,7 +17,11 @@ def test_cell_files(cell):
     conf = {c["name"]: c for c in BENCH["configs"]}[cell["config"]]
     cdoc = json.load(open(ROOT / conf["file"]))
     assert cdoc["name"] == conf["name"] and cdoc["source"] == conf["source"]
-    assert cdoc["reduced"] == conf["reduced"] == []
+    # a cut key has its published value and reaches the program
+    assert cdoc["reduced"] == conf["reduced"]
+    overrides = cdoc["program"].get("overrides", {})
+    for key in cdoc["reduced"]:
+        assert key in cdoc["published"] and key in overrides
     mix = json.load(open(ROOT / "chipbench" / "traffic"
                          / f"{cell['traffic']}.json"))
     assert mix["kind"] in ("requests", "sessions")
@@ -49,10 +53,9 @@ def _flat(tree, path=""):
 def test_weights_match_program_layout(conf):
     import jax
     import jax.numpy as jnp
-    from repro.configs import get_config
     from repro.models import model as M
     cdoc = json.load(open(ROOT / conf["file"]))
     want = jax.eval_shape(lambda: M.init(
-        get_config(cdoc["program"]["model"]), jax.random.PRNGKey(0),
+        arch.load(cdoc).program_config(cdoc), jax.random.PRNGKey(0),
         jnp.bfloat16))
     assert _flat(weights.shapes(cdoc)) == _flat(want)

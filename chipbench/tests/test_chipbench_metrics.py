@@ -10,6 +10,7 @@ import pytest
 
 from chipbench import work
 from chipbench.bench import StepLog
+from repro.engine.scheduler import EngineMetrics
 
 ROOT = Path(__file__).resolve().parents[2]
 Q3 = json.load(open(ROOT / "chipbench" / "configs" / "qwen3-0.6b.json"))
@@ -32,7 +33,8 @@ def ctx(**kw):
                               "prefill_step": 0.005}}
     base = dict(cdoc=Q3, peak=PEAK, trace=trace, traced_steps=steps,
                 window_steps=steps, records=[], memory={},
-                serve=NS(step_count=0, window_compiles=0))
+                serve=NS(step_count=0, window_compiles=0), step_records=[],
+                counters_open=EngineMetrics(), counters_close=EngineMetrics())
     base.update(kw)
     return NS(**base)
 

@@ -13,7 +13,7 @@ import pytest
 
 from chipbench import program_trace as P
 from chipbench import trace as T
-from chipbench.run import KERNELS
+from chipbench.arch.qwen_dense import KERNELS
 from repro.engine.tracing import StepRecord
 
 DATA = Path(__file__).resolve().parent / "data"

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import reference, weights
+from chipbench import arch, reference, weights
 
 DATA = Path(__file__).resolve().parent / "data"
 PAGE, CHUNK, NB = 8, 16, 8
@@ -48,7 +48,7 @@ def test_reference_matches_paged_prefill_then_decode(conf):
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, cdoc["vocab_size"], 37).tolist()
     got, seq = program_logits(cdoc, w, prompt, n_decode=6)
-    toks = np.zeros(reference.BLOCK_Q, np.int32)
+    toks = np.zeros(arch.load(cdoc).BLOCK_Q, np.int32)
     toks[:len(seq)] = seq
     sel = len(prompt) - 1 + np.arange(len(got))
     want = np.asarray(reference.logits(cdoc, w, toks, sel))
@@ -62,7 +62,7 @@ def test_control_departs_from_reference(quant):
     cdoc = json.load(open(DATA / "tiny-qwen3.json"))
     w = weights.make(cdoc, jax.random.PRNGKey(4), jnp.float32)
     toks = np.random.default_rng(1).integers(
-        0, cdoc["vocab_size"], reference.BLOCK_Q).astype(np.int32)
+        0, cdoc["vocab_size"], arch.load(cdoc).BLOCK_Q).astype(np.int32)
     sel = np.arange(64)
     ref = np.asarray(reference.logits(cdoc, w, toks, sel))
     low = np.asarray(reference.logits(cdoc, w, toks, sel, quant=quant))

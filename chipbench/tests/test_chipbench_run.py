@@ -139,3 +139,25 @@ def test_fault_makes_run_incorrect(fault):
         if retrace:
             jax.clear_caches()
     assert not out["correct"], out["checks"]
+
+
+def test_trace_slice_waits_for_a_prefill():
+    """A slice that opens after the window's prefills are done stays
+    open until a step carries a prefill chunk (the arrival at 1.5 s),
+    and one that meets none closes at its cap."""
+    from chipbench.bench import Bench
+    from chipbench.traffic import Arrival
+
+    bench = Bench(CDOC, 3, jax.devices()[0])
+    bench.warm_programs(MIX["context_cap"])
+
+    def arrival(due):
+        return Arrival(due, list(range(1, 21)), 8, "u", None, 10.0)
+
+    for later, lo, hi in (([arrival(1.5)], 1.5, 3.0), ([], 3.0, 9.0)):
+        res = bench.serve([arrival(0.0)] + later, 0.0, 0.5, 5.0,
+                          (0.6, 0.7, 3.0))
+        t = res.trace
+        assert lo <= t["t_stop"] - res.window_open < hi
+        traced = [s for s in res.steps if t["t_start"] <= s.t < t["t_stop"]]
+        assert any(s.chunks for s in traced) == bool(later)

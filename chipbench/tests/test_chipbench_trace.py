@@ -6,7 +6,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import trace as T
-from chipbench.run import KERNELS
+from chipbench.arch.qwen_dense import KERNELS
 
 DATA = Path(__file__).resolve().parent / "data"
 

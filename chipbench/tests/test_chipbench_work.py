@@ -6,6 +6,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import work
+from chipbench.arch import qwen_dense
 
 CONF = Path(__file__).resolve().parents[1] / "configs"
 Q3 = json.load(open(CONF / "qwen3-0.6b.json"))
@@ -35,12 +36,12 @@ def test_prefill_attention_qwen2():
     (Q2, 1536 * 1536 + 2 * 1536 * 256 + 1536 * 1536 + 3 * 1536 * 8960),
 ])
 def test_matmul_params(conf, per_layer):
-    assert work.matmul_params_per_layer(conf) == per_layer
+    assert qwen_dense.matmul_params_per_layer(conf) == per_layer
 
 
 def test_step_flops_qwen3_two_rows_one_chunk():
     dec, chunks = [100, 300], [(0, 256)]
-    dense = 2 * 258 * 28 * work.matmul_params_per_layer(Q3)
+    dense = 2 * 258 * 28 * qwen_dense.matmul_params_per_layer(Q3)
     attn = 28 * (4 * 16 * 128 * 400 + 4 * 16 * 128 * (256 * 257 / 2))
     unembed = 2 * 3 * 1024 * 151_936
     assert work.step_flops(Q3, dec, chunks) == pytest.approx(

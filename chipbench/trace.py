@@ -9,6 +9,8 @@ the tests check it on a small recorded trace.
   and its string stats joined into ``meta``.
 - Host spans: events on host threads, among them the harness's own
   ``TraceAnnotation`` spans.
+- The program's own spans (``chipbench/program_trace.py``), with their
+  stats, which ``reduce`` hands to ``program_trace.reduce``.
 
 ``reduce`` computes, over the traced window: the union of device-op
 intervals (busy), the time of each named kernel and of each step
@@ -25,6 +27,8 @@ import re
 import shutil
 from collections import defaultdict
 from typing import Dict, List, Optional
+
+from chipbench import program_trace
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -55,9 +59,10 @@ def load(trace_dir: str, cleanup: bool = True) -> dict:
                 elif ev.name in SPANS:
                     host.append({"name": ev.name, "start": start,
                                  "dur": dur})
+    program = program_trace.spans(pd)
     if cleanup:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    return {"device": device, "host": host}
+    return {"device": device, "host": host, "program": program}
 
 
 def _union(intervals) -> List[tuple]:
@@ -98,8 +103,9 @@ def reduce(events: dict, window_ns: float,
            kernels: Optional[Dict[str, str]] = None,
            top: int = 10) -> dict:
     """Busy and idle time, kernel time, each step program's time, top
-    ops and labelled idle gaps.  ``kernels`` maps a kernel's name to the
-    regex that finds its ops."""
+    ops, labelled idle gaps, and under ``program`` the reduction of the
+    program's own spans (``program_trace.reduce``).  ``kernels`` maps a
+    kernel's name to the regex that finds its ops."""
     dev = events["device"]
     planes = sorted({e["plane"] for e in dev})
     ops = [e for e in dev if e["line"] == OPS_LINE]
@@ -128,6 +134,8 @@ def reduce(events: dict, window_ns: float,
     out["device_ops"] = [[k, v / 1e9] for k, v in sorted(
         by_op.items(), key=lambda kv: -kv[1])[:top]]
     out["idle_gaps"] = label_gaps(busy, events["host"], top)
+    out["program"] = program_trace.reduce(dev, events.get("program", []),
+                                          top)
     return out
 
 
